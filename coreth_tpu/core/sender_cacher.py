@@ -109,26 +109,17 @@ class TxSenderCacher:
                             "core/sender_cacher/recover_error").inc()
             _shard_timer.update(time.perf_counter() - t0)
 
-        from ..native import secp
-
-        if secp.available():
-            # strided shards across the CPU-thread pool, each pinned to
-            # ONE native thread: the Python-side item building (RLP +
-            # sig-hash keccak, GIL-bound) of shard k overlaps the
-            # GIL-released native recovery of the other shards — one big
-            # native call would serialise all the item building in front
-            # of it (sender_cacher.go:88-115's strided split, batch-first)
-            n = min(self.threads, max(1, len(txs) // _SHARD_MIN))
-            if n <= 1:
-                futs = [self._pool.submit(work_batch, txs)]
-            else:
-                futs = [self._pool.submit(work_batch, txs[i::n], i, n, 1)
-                        for i in range(n)]
+        # strided shards across the CPU-thread pool, each pinned to ONE
+        # native thread: the Python-side item building (RLP + sig-hash
+        # keccak, GIL-bound) of shard k overlaps the GIL-released native
+        # recovery of the other shards — one big native call would
+        # serialise all the item building in front of it
+        # (sender_cacher.go:88-115's strided split, batch-first)
+        n = min(self.threads, max(1, len(txs) // _SHARD_MIN))
+        if n <= 1:
+            futs = [self._pool.submit(work_batch, txs)]
         else:
-            # pure-Python path: strided split like the reference
-            # (sender_cacher.go:100-108) so the pool overlaps work
-            n = min(self.threads, len(txs))
-            futs = [self._pool.submit(work_batch, txs[i::n], i, n)
+            futs = [self._pool.submit(work_batch, txs[i::n], i, n, 1)
                     for i in range(n)]
         with self._lock:
             self._batches[token] = futs

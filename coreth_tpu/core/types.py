@@ -233,10 +233,7 @@ class Signer:
         # scalar multiply (~13ms) on the insert path
         from ..native import secp
 
-        if secp.available():
-            addr = secp.recover_one(msg, recid, tx.r, tx.s)
-        else:
-            addr = secp256k1.recover_address(msg, recid, tx.r, tx.s)
+        addr = secp.recover_one(msg, recid, tx.r, tx.s)
         if addr is None:
             raise ValueError("invalid signature")
         tx._sender = addr
@@ -256,8 +253,8 @@ class Signer:
 
     def sender_batch(self, txs, native_threads: int = 0) -> None:
         """Batch-recover senders into each tx's cache — the sender-cacher
-        drain (core/sender_cacher.go:88-115). Uses the native batched
-        secp256k1 when available; silently leaves invalid txs uncached so
+        drain (core/sender_cacher.go:88-115) through the native batched
+        secp256k1; silently leaves invalid txs uncached so
         the per-tx sender() surfaces the precise error later.
 
         native_threads is forwarded to the native recover pool (0 = its
@@ -269,18 +266,6 @@ class Signer:
 
         todo = [tx for tx in txs if tx._sender is None]
         if not todo:
-            return
-        if not secp.available():
-            for tx in todo:
-                try:
-                    self.sender(tx)
-                except Exception:
-                    # invalid signature: left uncached on purpose so the
-                    # insert path surfaces the precise error — but count,
-                    # a malformed-signature flood must be visible here too
-                    from ..metrics import count_drop
-
-                    count_drop("core/sender_batch/recover_error")
             return
         items = []
         ok_idx = []

@@ -3,8 +3,8 @@
 The reference's only native-adjacent pieces are its crypto deps (SURVEY.md
 §2.6). Here the native layer is the fast host-side keccak used below the TPU
 batch threshold and as the CPU baseline for benchmarks. Compiled lazily with
-g++ on first import; falls back to None (callers then use the pure-Python
-reference) when no toolchain is available.
+g++ on first use (native/_build.py); a failed build raises with the
+compiler's output.
 """
 
 from __future__ import annotations
@@ -17,11 +17,9 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "keccak.cpp")
-_LIB = os.path.join(_DIR, "libkeccak.so")
 
 _lock = threading.Lock()
 _lib = None
-_load_failed = False
 
 
 def default_cpu_threads() -> int:
@@ -42,19 +40,16 @@ def default_cpu_threads() -> int:
 
 
 def load():
-    """Return the ctypes lib, building it if needed, or None on failure."""
-    global _lib, _load_failed
-    if _lib is not None or _load_failed:
+    """Return the ctypes lib, building it if needed."""
+    global _lib
+    if _lib is not None:
         return _lib
     with _lock:
-        if _lib is not None or _load_failed:
+        if _lib is not None:
             return _lib
         from ._build import build_and_load
 
-        lib = build_and_load(_SRC, _LIB, timeout=120)
-        if lib is None:
-            _load_failed = True
-            return None
+        lib = build_and_load(_SRC, "libkeccak", timeout=120)
         lib.keccak256.argtypes = [
             ctypes.c_char_p, ctypes.c_uint64, ctypes.c_char_p,
         ]
@@ -74,12 +69,7 @@ _OUT32 = ctypes.c_char * 32  # hoisted: create_string_buffer per call is
 
 
 def keccak256(data: bytes) -> bytes:
-    lib = _lib
-    if lib is None:
-        lib = load()
-        if lib is None:
-            from ..ops.keccak_ref import keccak256 as ref
-            return ref(data)
+    lib = _lib if _lib is not None else load()
     out = _OUT32()
     lib.keccak256(data, len(data), out)
     return out.raw
@@ -91,9 +81,6 @@ def keccak256_batch(msgs, threads: int = 0) -> list:
     if n == 0:
         return []
     lib = load()
-    if lib is None:
-        from ..ops.keccak_ref import keccak256 as ref
-        return [ref(m) for m in msgs]
     blob = b"".join(msgs)
     offsets = np.zeros(n + 1, dtype=np.uint64)
     np.cumsum(np.fromiter((len(m) for m in msgs), np.uint64, count=n), out=offsets[1:])

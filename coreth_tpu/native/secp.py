@@ -4,7 +4,7 @@ core/sender_cacher.go:88-115 over cgo libsecp256k1).
 
 `recover_batch` takes parallel arrays for the whole tx slice and returns
 (addresses, ok-flags); the pure-Python `crypto.secp256k1` stays the
-verification oracle and the fallback when no toolchain exists.
+verification oracle.
 """
 
 from __future__ import annotations
@@ -18,26 +18,21 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "secp256k1.cpp")
-_LIB = os.path.join(_DIR, "libsecp256k1_tpu.so")
 
 _lock = threading.Lock()
 _lib = None
-_load_failed = False
 
 
 def load():
-    global _lib, _load_failed
-    if _lib is not None or _load_failed:
+    global _lib
+    if _lib is not None:
         return _lib
     with _lock:
-        if _lib is not None or _load_failed:
+        if _lib is not None:
             return _lib
         from ._build import build_and_load
 
-        lib = build_and_load(_SRC, _LIB)
-        if lib is None:
-            _load_failed = True
-            return None
+        lib = build_and_load(_SRC, "libsecp256k1_tpu")
         lib.secp_recover_batch.restype = None
         lib.secp_recover_batch.argtypes = [
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
@@ -51,18 +46,12 @@ def load():
         return _lib
 
 
-def available() -> bool:
-    return load() is not None
-
-
 def recover_batch(
     items: Sequence[Tuple[bytes, int, int, int]], threads: int = 0
 ) -> List[Optional[bytes]]:
     """items: (msg_hash32, recid, r, s) per signature. Returns the 20-byte
     sender address per item, None where the signature is invalid."""
     lib = load()
-    if lib is None:
-        raise RuntimeError("native secp256k1 unavailable (no g++?)")
     n = len(items)
     if n == 0:
         return []
@@ -92,13 +81,10 @@ def recover_batch(
 
 
 def recover_one(msg_hash: bytes, recid: int, r: int, s: int) -> Optional[bytes]:
-    """One signature -> 20-byte address, or None if invalid. Raises
-    RuntimeError when the native library is unavailable — callers that
-    lose the sender-cacher race use this instead of the pure-Python
+    """One signature -> 20-byte address, or None if invalid. Callers
+    that lose the sender-cacher race use this instead of the pure-Python
     scalar path (~3 orders of magnitude slower per recovery)."""
     lib = load()
-    if lib is None:
-        raise RuntimeError("native secp256k1 unavailable (no g++?)")
     if not (0 < r < 2**256 and 0 < s < 2**256 and 0 <= recid <= 3):
         return None
     sig = r.to_bytes(32, "big") + s.to_bytes(32, "big")

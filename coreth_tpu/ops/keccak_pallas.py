@@ -28,6 +28,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .keccak_ref import _ROUND_CONSTANTS, _ROTC
 
 WORDS_PER_BLOCK = 34
+PALLAS_LANES = 1024  # lane multiple the segment kernel's grid tiles
 _RC_LO = tuple(rc & 0xFFFFFFFF for rc in _ROUND_CONSTANTS)
 _RC_HI = tuple(rc >> 32 for rc in _ROUND_CONSTANTS)
 
@@ -216,7 +217,8 @@ def segment_keccak_pallas(words: jax.Array, interpret: bool = False) -> jax.Arra
     segments). State lives in VMEM across every round and block — one HBM
     read of the message words, one HBM write of digests."""
     p, num_blocks, _ = words.shape
-    assert p % 1024 == 0, "pallas segment batch must be a multiple of 1024 lanes"
+    assert p % PALLAS_LANES == 0, \
+        "pallas segment batch must be a multiple of 1024 lanes"
     rows = p // 128
     w = jnp.transpose(words, (1, 2, 0)).reshape(
         num_blocks, WORDS_PER_BLOCK, rows, 128
@@ -246,10 +248,24 @@ def staged_seg_impl(interpret: bool = False):
     static at trace time)."""
 
     def impl(words):
-        if words.shape[0] % 1024 == 0:
+        if words.shape[0] % PALLAS_LANES == 0:
             return segment_keccak_pallas(words, interpret=interpret)
         from .keccak_staged import _segment_keccak
 
         return _segment_keccak(words)
 
+    impl.pallas_lanes = PALLAS_LANES
     return impl
+
+
+def count_segments(layer: str, seg_impl, lanes) -> None:
+    """Count one commit's segments by the kernel that hashes them:
+    `<layer>/segments/pallas` for lane counts a staged_seg_impl sends to
+    the Pallas kernel, `<layer>/segments/xla` for the rest."""
+    from ..metrics import default_registry
+
+    tile = getattr(seg_impl, "pallas_lanes", 0)
+    n_pallas = sum(1 for n in lanes if tile and n % tile == 0)
+    default_registry.counter(f"{layer}/segments/pallas").inc(n_pallas)
+    default_registry.counter(f"{layer}/segments/xla").inc(
+        len(lanes) - n_pallas)

@@ -67,10 +67,7 @@ def _check_width(n: int, what: str) -> None:
 
 def process_count() -> int:
     """Number of jax processes in this runtime (1 = single-process)."""
-    try:
-        return int(jax.process_count())
-    except AttributeError:  # very old jax without the multi-process API
-        return 1
+    return int(jax.process_count())
 
 
 def mesh_spans_processes(mesh: Mesh) -> bool:

@@ -27,6 +27,22 @@ SNAPSHOT_ROOT_KEY = b"SnapshotRoot"
 SNAPSHOT_BLOCK_HASH_KEY = b"SnapshotBlockHash"
 
 
+# exact key lengths of the flat snapshot schema. The prefixes are single
+# bytes sharing the keyspace with 32-byte hash-keyed trie nodes, so a
+# prefix match alone also catches every node whose hash starts with the
+# prefix byte.
+ACCOUNT_KEY_LEN = 1 + 32
+STORAGE_KEY_LEN = 1 + 32 + 32
+
+
+def iterate_snapshot(diskdb, prefix: bytes, key_len: int,
+                     start: bytes = b""):
+    """(key, value) of the snapshot entries under prefix: exact schema
+    length only, never a trie node that shares the prefix byte."""
+    return ((k, v) for k, v in diskdb.iterate(prefix=prefix, start=start)
+            if len(k) == key_len)
+
+
 def account_snapshot_key(addr_hash: bytes) -> bytes:
     return SNAPSHOT_ACCOUNT_PREFIX + addr_hash
 
@@ -342,7 +358,8 @@ class Tree:
                     pfx = SNAPSHOT_ACCOUNT_PREFIX
                     yield depth, (
                         (k[len(pfx):], v)
-                        for k, v in layer.diskdb.iterate(prefix=pfx, start=start)
+                        for k, v in iterate_snapshot(
+                            layer.diskdb, pfx, ACCOUNT_KEY_LEN, start)
                     )
                 else:
                     entries = dict.fromkeys(layer.destructs, b"")
@@ -393,11 +410,12 @@ class Tree:
         from ..trie.node import EMPTY_ROOT
 
         batch = self.diskdb.new_batch()
-        # wipe any stale snapshot data
-        for k, _ in list(self.diskdb.iterate(prefix=SNAPSHOT_ACCOUNT_PREFIX)):
-            batch.delete(k)
-        for k, _ in list(self.diskdb.iterate(prefix=SNAPSHOT_STORAGE_PREFIX)):
-            batch.delete(k)
+        # wipe any stale snapshot data (and nothing else: trie nodes share
+        # the prefix bytes)
+        for prefix, key_len in ((SNAPSHOT_ACCOUNT_PREFIX, ACCOUNT_KEY_LEN),
+                                (SNAPSHOT_STORAGE_PREFIX, STORAGE_KEY_LEN)):
+            for k, _ in list(iterate_snapshot(self.diskdb, prefix, key_len)):
+                batch.delete(k)
         if root != EMPTY_ROOT:
             from ..trie.iterator import iterate_leaves
             from .account import Account
@@ -428,7 +446,8 @@ class Tree:
         from .statedb import _slim_to_account
 
         st = StackTrie()
-        entries = sorted(self.diskdb.iterate(prefix=SNAPSHOT_ACCOUNT_PREFIX))
+        entries = sorted(iterate_snapshot(
+            self.diskdb, SNAPSHOT_ACCOUNT_PREFIX, ACCOUNT_KEY_LEN))
         for k, slim in entries:
             addr_hash = k[len(SNAPSHOT_ACCOUNT_PREFIX):]
             acct = _slim_to_account(slim)

@@ -93,8 +93,10 @@ class LeafsRequestHandler:
             return None
         import time as _time
 
-        from ..state.snapshot import (SNAPSHOT_ACCOUNT_PREFIX,
-                                      SNAPSHOT_STORAGE_PREFIX, SnapshotError)
+        from ..state.snapshot import (ACCOUNT_KEY_LEN,
+                                      SNAPSHOT_ACCOUNT_PREFIX,
+                                      SNAPSHOT_STORAGE_PREFIX, SnapshotError,
+                                      iterate_snapshot)
         from ..state.statedb import _slim_to_account
 
         disk = self.snaps.disk_layer
@@ -114,8 +116,8 @@ class LeafsRequestHandler:
                 convert = lambda v: v
             else:
                 pfx = SNAPSHOT_ACCOUNT_PREFIX
-                it = ((k[len(pfx):], v)
-                      for k, v in disk.diskdb.iterate(pfx, req.start))
+                it = ((k[len(pfx):], v) for k, v in iterate_snapshot(
+                    disk.diskdb, pfx, ACCOUNT_KEY_LEN, req.start))
                 # snapshot stores slim account RLP; the trie stores full
                 convert = lambda v: _slim_to_account(v).encode()
             for k, v in it:

@@ -337,13 +337,15 @@ class PlannedGraphBuilder:
         """Execute on device; assigns flags.hash on every hashed node,
         heals value holes, returns the final (account) root digest.
 
-        Raises _TooManySegments when the graph exceeds the executor's
-        segment table; callers fall back to the level-batched hasher."""
-        from ..metrics import phase_timer
+        Raises TooManySegments when the graph exceeds the executor's
+        segment table; callers fall back to the level-batched hasher,
+        and trie/planned/too_many_segments counts each such fallback."""
+        from ..metrics import default_registry, phase_timer
 
         with phase_timer("planned/phase/plan"):
             built = self.build()
         if built is None:
+            default_registry.counter("trie/planned/too_many_segments").inc()
             raise TooManySegments()
         specs, flat_words, dst, child, shift, root_pos, total_lanes = built
         if planned is None:

@@ -4,28 +4,31 @@ from __future__ import annotations
 
 import os
 
+# the persistent compile cache when JAX_COMPILATION_CACHE_DIR is unset:
+# one fixed directory inside the checkout (listed in .gitignore) — a
+# cache's path is part of its key, so it must not move between runs
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".jax_cache")
+
 _cache_enabled = False
 
 
-def enable_compilation_cache(path: str | None = None) -> None:
+def enable_compilation_cache() -> None:
     """Persist XLA compilations across processes.
 
-    The keccak kernel compiles one program per (batch-bucket, block-bucket)
-    shape; with the disk cache a fresh process (bench run, node restart)
-    reuses them instead of paying the multi-second compile per shape again.
+    The keccak kernels compile one program per shape bucket; with the
+    disk cache a fresh process (a node restart, the next run) reuses them
+    instead of paying each compile again. Where JAX_COMPILATION_CACHE_DIR
+    is set, JAX already reads it and nothing is set here; otherwise the
+    cache is CHECKOUT_CACHE_DIR.
     """
     global _cache_enabled
     if _cache_enabled:
         return
     import jax
 
-    cache_dir = path or os.environ.get(
-        "CORETH_TPU_JAX_CACHE", os.path.expanduser("~/.cache/coreth_tpu_xla")
-    )
-    os.makedirs(cache_dir, exist_ok=True)
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:
-        pass  # older jax without the knobs: cache is an optimization only
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     _cache_enabled = True

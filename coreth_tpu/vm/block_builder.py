@@ -93,9 +93,13 @@ class BlockBuilder:
             return
 
         def fire():
+            # read the pools before taking self.lock: a tx arrival calls
+            # signal_txs_ready while it holds TxPool.mu, so asking the
+            # pool under self.lock is the reverse order and deadlocks
+            need = self.need_to_build()
             with self.lock:
                 self._timer = None
-                if self.need_to_build():
+                if need:
                     self._mark_building()
 
         self._timer = threading.Timer(self.retry_delay, fire)

@@ -110,18 +110,18 @@ class StateSyncClient:
             # but not in the synced state can never survive as phantoms
             # (the reference resets snapshot generation on sync start)
             from ..state.snapshot import (
+                ACCOUNT_KEY_LEN,
                 SNAPSHOT_ACCOUNT_PREFIX,
                 SNAPSHOT_STORAGE_PREFIX,
+                STORAGE_KEY_LEN,
+                iterate_snapshot,
             )
 
             batch = diskdb.new_batch()
-            # exact schema lengths only: hash-keyed trie nodes (32 B) and
-            # other rawdb keys can share a first byte with these prefixes
-            for prefix, klen in ((SNAPSHOT_ACCOUNT_PREFIX, 33),
-                                 (SNAPSHOT_STORAGE_PREFIX, 65)):
-                for k, _v in diskdb.iterate(prefix):
-                    if len(k) == klen:
-                        batch.delete(k)
+            for prefix, klen in ((SNAPSHOT_ACCOUNT_PREFIX, ACCOUNT_KEY_LEN),
+                                 (SNAPSHOT_STORAGE_PREFIX, STORAGE_KEY_LEN)):
+                for k, _v in list(iterate_snapshot(diskdb, prefix, klen)):
+                    batch.delete(k)
             batch.write()
         diskdb.put(SYNC_SUMMARY_KEY, summary.encode())
         self.state_sync(summary)

@@ -99,13 +99,15 @@ class VM:
         config_bytes: bytes = b"",
     ) -> None:
         self.ctx = ctx
-        if config is None and config_bytes:
-            # JSON blob from the node (vm.go:326-334) → runtime knobs
+        if config_bytes:
+            # JSON blob from the node (vm.go:326-334) → runtime knobs; a
+            # VMConfig passed beside it contributes only its clock
             from .config import parse_config
 
             full = parse_config(config_bytes)
             self.full_config = full
             config = VMConfig(
+                clock=config.clock if config is not None else None,
                 pruning=full.pruning_enabled,
                 commit_interval=full.commit_interval,
                 mempool_size=full.tx_pool_global_slots,

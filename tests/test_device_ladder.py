@@ -121,7 +121,7 @@ class TestHostFallback:
         lad.configure(max_retries=0)
 
         def broken(msgs):
-            raise RuntimeError("tunnel wedged")
+            raise RuntimeError("device wedged")
 
         lk = LadderedKeccak(broken, ladder=lad)
         assert lk(self.MSGS) == keccak256_batch(self.MSGS)
@@ -148,7 +148,9 @@ class TestProbes:
         lad.add_listener(_collect(events))
         lad.demote("test")
         deadline = time.monotonic() + 15
-        while not lad.healthy and time.monotonic() < deadline:
+        # listeners hear "promote" just after the state flips to healthy
+        while not (lad.healthy and events and events[-1][0] == "promote") \
+                and time.monotonic() < deadline:
             time.sleep(0.01)
         assert lad.healthy, f"never re-promoted: {lad.status()}"
         kinds = [k for k, _ in events]

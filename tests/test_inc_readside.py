@@ -14,11 +14,7 @@ import numpy as np
 import pytest
 
 from coreth_tpu.crypto import keccak256
-from coreth_tpu.native.mpt import IncrementalTrie, load_inc
-
-pytestmark = pytest.mark.skipif(
-    load_inc() is None, reason="native incremental planner unavailable")
-
+from coreth_tpu.native.mpt import IncrementalTrie
 
 def _items(rng, n):
     d = {rng.randbytes(32): rng.randbytes(rng.randint(1, 90))

@@ -8,16 +8,10 @@ planned-executor seam.
 
 import random
 
-import pytest
 
-from coreth_tpu.native.mpt import EMPTY_ROOT, IncrementalTrie, load_inc
+from coreth_tpu.native.mpt import EMPTY_ROOT, IncrementalTrie
 from coreth_tpu.trie.hasher import Hasher
 from coreth_tpu.trie.trie import Trie
-
-pytestmark = pytest.mark.skipif(
-    load_inc() is None, reason="native incremental planner unavailable"
-)
-
 
 def oracle_root(items: dict) -> bytes:
     t = Trie()

@@ -14,16 +14,6 @@ from coreth_tpu.native.mpt import plan_from_items
 from coreth_tpu.trie.trie import Trie
 
 
-@pytest.fixture(autouse=True)
-def _require_native():
-    # lazy: the g++ build only runs when these tests are selected, not at
-    # collection time
-    from coreth_tpu.native.mpt import load
-
-    if load() is None:
-        pytest.skip("native planner unavailable")
-
-
 def _random_items(n, vmin, vmax, seed):
     rng = random.Random(seed)
     items = {}

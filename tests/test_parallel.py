@@ -152,11 +152,9 @@ def test_planned_commit_sharded_over_mesh():
     root bit-exactly."""
     import random
 
-    from coreth_tpu.native.mpt import load, plan_from_items
+    from coreth_tpu.native.mpt import plan_from_items
     from coreth_tpu.parallel import make_mesh, planned_commit_over_mesh
 
-    if load() is None:
-        pytest.skip("native planner unavailable")
     rng = random.Random(31)
     items = [(rng.randbytes(32), rng.randbytes(rng.randint(40, 90)))
              for _ in range(900)]
@@ -174,11 +172,9 @@ def test_resident_executor_sharded_over_mesh():
     the resident state actually spanning every device."""
     import random
 
-    from coreth_tpu.native.mpt import IncrementalTrie, load_inc
+    from coreth_tpu.native.mpt import IncrementalTrie
     from coreth_tpu.parallel import make_mesh, resident_executor_over_mesh
 
-    if load_inc() is None:
-        pytest.skip("native incremental planner unavailable")
     rng = random.Random(32)
     items = sorted(
         {rng.randbytes(32): rng.randbytes(rng.randint(1, 90))
@@ -208,11 +204,9 @@ def test_resident_executor_sharded_over_2d_mesh():
     over BOTH axes (host-contiguous blocks), roots stay bit-exact."""
     import random
 
-    from coreth_tpu.native.mpt import IncrementalTrie, load_inc
+    from coreth_tpu.native.mpt import IncrementalTrie
     from coreth_tpu.parallel import make_mesh_2d, resident_executor_over_mesh
 
-    if load_inc() is None:
-        pytest.skip("native incremental planner unavailable")
     rng = random.Random(33)
     items = sorted(
         {rng.randbytes(32): rng.randbytes(50) for _ in range(500)}.items())

@@ -16,10 +16,7 @@ import random
 import numpy as np
 import pytest
 
-from coreth_tpu.native.mpt import IncrementalTrie, load_inc
-
-pytestmark = pytest.mark.skipif(
-    load_inc() is None, reason="native incremental planner unavailable")
+from coreth_tpu.native.mpt import IncrementalTrie
 
 # widths 2 and 8 ride the slow tier: the parity sweep compiles two
 # fused mesh programs per width, and tier-1's budget holds widths

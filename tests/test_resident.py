@@ -18,13 +18,8 @@ import pytest
 from coreth_tpu.native.mpt import (
     EMPTY_ROOT,
     IncrementalTrie,
-    load_inc,
     plan_from_items,
 )
-
-pytestmark = pytest.mark.skipif(
-    load_inc() is None, reason="native incremental planner unavailable")
-
 
 def _executor():
     from coreth_tpu.ops.keccak_resident import ResidentExecutor

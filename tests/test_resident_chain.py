@@ -9,8 +9,6 @@ reorg (core/blockchain.go:1234,1034,1067,1424), hashdb interval commit
 (core/state_manager.go:126-186), statedb.go IntermediateRoot/Commit
 (statedb.go:952,1040)."""
 
-import pytest
-
 from coreth_tpu import params
 from coreth_tpu.consensus.dummy import new_dummy_engine
 from coreth_tpu.core.blockchain import BlockChain, CacheConfig
@@ -20,13 +18,9 @@ from coreth_tpu.core.state_manager import ResidentTrieWriter
 from coreth_tpu.core.types import Signer, Transaction
 from coreth_tpu.crypto.secp256k1 import priv_to_address
 from coreth_tpu.ethdb import MemoryDB
-from coreth_tpu.native.mpt import load_inc
 from coreth_tpu.state.database import Database
 from coreth_tpu.state.statedb import StateDB
 from coreth_tpu.trie.triedb import TrieDatabase
-
-pytestmark = pytest.mark.skipif(
-    load_inc() is None, reason="native incremental planner unavailable")
 
 KEY1 = b"\x11" * 32
 KEY2 = b"\x22" * 32

@@ -14,13 +14,10 @@ import pytest
 
 from coreth_tpu import fault
 from coreth_tpu.metrics import default_registry
-from coreth_tpu.native.mpt import (DeviceWedgedError, load_inc,
+from coreth_tpu.native.mpt import (DeviceWedgedError,
                                    plan_from_items)
 from coreth_tpu.trie.resident_mirror import ResidentAccountMirror
 from coreth_tpu.trie.trie import Trie
-
-pytestmark = pytest.mark.skipif(
-    load_inc() is None, reason="native incremental planner unavailable")
 
 WIDTHS = (1, 2, 4, 8)
 
@@ -250,7 +247,7 @@ def test_mesh_ladder_demotion_bit_exact():
     assert m.root_of(_hash(4)) == _py_oracle(s4)
 
 
-def test_mesh_demotion_rebuild_wedge_escalates_to_host():
+def test_mesh_demotion_rebuild_wedge_escalates_to_host(monkeypatch):
     """When the single-device rebuild inside the demotion ALSO wedges
     (a dead backend, not a dead mesh), the ladder walks straight
     through to the host with the same commit still answered
@@ -263,24 +260,13 @@ def test_mesh_demotion_rebuild_wedge_escalates_to_host():
     b1 = _batch(rng, state, 8)
     s1 = _apply(state, b1)
     assert m.verify(m.GENESIS, _hash(1), b1) == _oracle(s1)
-    # a hanging d2h sync + a watchdog too tight for any rebuild: the
-    # demotion's own recommit wedges, _demote_mesh returns False, and
-    # the host takeover finishes the job
+    # a dead backend: every executor's dispatch hangs, so the
+    # demotion's own single-device recommit wedges too, _demote_mesh
+    # returns False, and the host takeover finishes the job
     fail0 = default_registry.counter(
         "state/resident/mesh_demotion_failures").count()
-
-    class _Hang:
-        def run(self, export):
-            threading.Event().wait()
-
-        def __getattr__(self, name):
-            return getattr(m_ex, name)
-
-        def __setattr__(self, name, value):
-            setattr(m_ex, name, value)
-
-    m_ex = m.ex
-    m.ex = _Hang()
+    monkeypatch.setattr(type(m.ex), "run",
+                        lambda self, export: threading.Event().wait())
     m.device_timeout = 0.2
     b2 = _batch(rng, s1, 8)
     s2 = _apply(s1, b2)

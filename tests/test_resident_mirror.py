@@ -7,12 +7,8 @@ import random
 
 import pytest
 
-from coreth_tpu.native.mpt import load_inc, plan_from_items
+from coreth_tpu.native.mpt import plan_from_items
 from coreth_tpu.trie.resident_mirror import MirrorError, ResidentAccountMirror
-
-pytestmark = pytest.mark.skipif(
-    load_inc() is None, reason="native incremental planner unavailable")
-
 
 @pytest.fixture(autouse=True)
 def _pin_device_path(monkeypatch):

@@ -7,18 +7,15 @@ window-deep); the periodic spot-check must settle the window before
 reading the device store back."""
 
 import random
+import threading
 
 import pytest
 
 from coreth_tpu import fault
 from coreth_tpu.metrics import default_registry
-from coreth_tpu.native.mpt import load_inc, plan_from_items
+from coreth_tpu.native.mpt import plan_from_items
 from coreth_tpu.trie.resident_mirror import MirrorError, ResidentAccountMirror
 from coreth_tpu.trie.trie import Trie
-
-pytestmark = pytest.mark.skipif(
-    load_inc() is None, reason="native incremental planner unavailable")
-
 
 @pytest.fixture(autouse=True)
 def _pin_device_path(monkeypatch):
@@ -309,7 +306,7 @@ def test_mid_pipeline_hang_drains_on_host_bit_exact():
     assert m.verify(parent, _hash(3), batch) == _oracle(state)
 
 
-def test_dispatch_wedge_lands_current_block_on_host():
+def test_dispatch_wedge_lands_current_block_on_host(monkeypatch):
     """A wedge at DISPATCH time (not drain): the current block's open
     scope sits on top of the window's scopes. The mirror must fold it
     away, land the window, then re-apply and commit this block on the
@@ -322,8 +319,10 @@ def test_dispatch_wedge_lands_current_block_on_host():
     s1 = _apply(genesis, b1)
     m.verify(m.GENESIS, _hash(1), b1, expected_root=_oracle(s1))
 
-    # wedge the NEXT dispatch: its program sync (inside dispatch when a
-    # watchdog is armed) parks on the failpoint
+    # wedge the NEXT dispatch: the device never answers the program,
+    # and the window's pending sync parks on the failpoint
+    monkeypatch.setattr(type(m.ex), "run",
+                        lambda self, export: threading.Event().wait())
     m.device_timeout = 0.4
     fault.set_failpoint("resident/before_absorb", "hang")
     b2 = _batch(rng, s1, 10)

@@ -10,10 +10,6 @@ from coreth_tpu.core.types import Signer, Transaction
 from coreth_tpu.crypto import secp256k1 as py_secp
 from coreth_tpu.native import secp
 
-pytestmark = pytest.mark.skipif(not secp.available(),
-                                reason="native secp256k1 unavailable")
-
-
 def test_recover_batch_parity_random():
     rng = random.Random(7)
     items, expect = [], []

@@ -133,6 +133,26 @@ class TestTree:
         layer = tree.snapshot(b"\x01" * 32)
         assert layer.account(ah) == b""  # deleted marker
 
+    @pytest.mark.parametrize("first", [b"a", b"o"])
+    def test_generation_keeps_nodes_sharing_a_prefix_byte(self, first):
+        """Trie nodes are keyed by 32-byte hash, so about 1 in 128 starts
+        with a snapshot prefix byte: generation wipes stale snapshot
+        entries and must leave those nodes on disk, and the disk layer's
+        account iterator must not yield them."""
+        diskdb = MemoryDB()
+        tdb = TrieDatabase(diskdb)
+        st = StateDB(EMPTY_ROOT, Database(tdb))
+        st.add_balance(ADDR, 100)
+        root = st.commit()
+        tdb.commit(root)
+        node_key = first + b"\x5a" * 31
+        diskdb.put(node_key, b"node blob")
+        tree = Tree(diskdb, tdb, root)
+        assert diskdb.get(node_key) == b"node blob"
+        keys = [k for k, _ in tree.account_iterator(root)]
+        assert keys and all(len(k) == 32 for k in keys)
+        assert tree.verify_root(root)
+
     def test_missing_parent_rejected(self):
         diskdb = MemoryDB()
         tdb = TrieDatabase(diskdb)

@@ -383,13 +383,9 @@ def test_two_vm_sync_into_resident_client():
     from coreth_tpu.core.genesis import GenesisAccount
     from coreth_tpu.core.state_manager import ResidentTrieWriter
     from coreth_tpu.core.types import Signer, Transaction
-    from coreth_tpu.native.mpt import load_inc
     from coreth_tpu.vm.shared_memory import Memory
     from coreth_tpu.vm.syncervm import StateSyncClient, StateSyncServer
     from coreth_tpu.vm.vm import VM, SnowContext, VMConfig
-
-    if load_inc() is None:
-        pytest.skip("native incremental planner unavailable")
 
     extra = {i.to_bytes(20, "big"): GenesisAccount(balance=10**12 + i)
              for i in range(1, 1200)}

@@ -489,6 +489,34 @@ class TestBlockBuilderThrottling:
         assert len(notes) >= 2
         vm.shutdown()
 
+    def test_retry_timer_never_holds_builder_lock_over_pool(self):
+        """A tx arrival signals the builder while holding TxPool.mu; the
+        retry timer asks the pool for work. Doing the latter under the
+        builder's lock is the reverse lock order: the two deadlock."""
+        import threading
+
+        vm, notes = self._vm_with_counter()
+        builder, pool = vm.block_builder, vm.txpool
+        asked = threading.Event()
+        stats = pool.stats
+
+        def stats_seen():
+            asked.set()
+            return stats()
+
+        pool.stats = stats_seen
+        builder.retry_delay = 0.0
+        with pool.mu:
+            builder.handle_generate_block()
+            assert asked.wait(5)  # the timer waits for pool.mu now
+            arrival = threading.Thread(target=builder.signal_txs_ready,
+                                       daemon=True)
+            arrival.start()
+            arrival.join(5)
+            assert not arrival.is_alive(), "builder lock held over the pool"
+        assert len(notes) == 1
+        vm.shutdown()
+
     def test_failed_build_reopens_gate(self):
         from coreth_tpu.vm.vm import VMError
 
