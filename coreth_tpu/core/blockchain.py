@@ -19,6 +19,7 @@ from typing import Callable, Dict, List, Optional
 from .. import rlp
 from ..fault import failpoint
 from ..fault import register as _register_failpoint
+from ..metrics import flight as _flight
 from ..metrics.flight import FlightRecorder
 from ..metrics.spans import span as _span
 from ..state.database import Database
@@ -229,45 +230,33 @@ class CacheConfig:
     db_retry_budget: int = 2
 
 
-# counter/timer families snapshotted around each insert so the flight
-# record carries per-block deltas (snapshot + plan-cache hits, keccak
-# batching) rather than process-cumulative values
-_FLIGHT_COUNTERS = (
-    "state/snap/hits", "state/snap/misses", "state/snap/generating",
-    "resident/plan_cache/hits", "resident/plan_cache/misses",
-    "resident/h2d_bytes", "resident/gather_bytes",
-    "resident/gather_bytes_modeled", "resident/absorb_d2h_bytes",
-    "resident/lean_wire_bytes",
-    "trie/keccak/batches", "trie/keccak/batch_msgs",
-)
-_FLIGHT_TIMERS = (
-    "resident/phase/commit", "resident/phase/plan", "resident/phase/export",
-    "resident/phase/scatter", "resident/phase/patch", "resident/phase/store",
-    "resident/phase/host_hash",
-)
-
-
 class _PhaseClock:
     """Times one insert phase into three sinks at once: the cumulative
     `<prefix><name>` registry timer (bench attribution; default
     `chain/phase/`), the in-flight block's flight record, and — when
-    tracing is on — a `<span_prefix><name>` span. One extra dict store
-    and two monotonic reads per phase over the old bare registry timer.
-    The insert pipeline reuses it with a `chain/pipeline/` prefix so its
-    stage timers are a parallel family, not an overwrite of the serial
-    attribution."""
+    tracing is on — a `<span_prefix><name>` span, carrying the block's
+    `number` when given. One extra dict store and two monotonic reads
+    per phase over the old bare registry timer. The insert pipeline
+    reuses it with a `chain/pipeline/` prefix so its stage timers are a
+    parallel family, not an overwrite of the serial attribution."""
 
-    __slots__ = ("_timer", "_phases", "_name", "_span_name", "_span", "_t0")
+    __slots__ = ("_timer", "_phases", "_name", "_span_name", "_number",
+                 "_span", "_t0")
 
     def __init__(self, name: str, phases: Dict[str, float], registry,
-                 prefix: str = "chain/phase/", span_prefix: str = "chain/"):
+                 prefix: str = "chain/phase/", span_prefix: str = "chain/",
+                 number: Optional[int] = None):
         self._timer = registry.timer(prefix + name)
         self._phases = phases
         self._name = name
         self._span_name = span_prefix + name
+        self._number = number
 
     def __enter__(self):
-        self._span = _span(self._span_name)
+        if self._number is None:
+            self._span = _span(self._span_name)
+        else:
+            self._span = _span(self._span_name, number=self._number)
         self._span.__enter__()
         self._t0 = time.monotonic()
         return self
@@ -1097,8 +1086,7 @@ class BlockChain:
         }
         with self._insert_recs_mu:
             self._insert_recs[block.hash()] = rec
-        counters0 = {n: _metrics.counter(n).count() for n in _FLIGHT_COUNTERS}
-        timers0 = {n: _metrics.timer(n).total() for n in _FLIGHT_TIMERS}
+        snap = _flight.snapshot(_metrics)
         phases = rec["phases"]
 
         t0 = time.monotonic()
@@ -1121,15 +1109,7 @@ class BlockChain:
             mirror = self.mirror
             rec["host_mode"] = (bool(mirror.host_mode)
                                 if mirror is not None else None)
-            rec["counters"] = {
-                n: _metrics.counter(n).count() - counters0[n]
-                for n in _FLIGHT_COUNTERS
-            }
-            rec["resident"] = {
-                n.rsplit("/", 1)[1]: d
-                for n in _FLIGHT_TIMERS
-                if (d := _metrics.timer(n).total() - timers0[n]) > 0.0
-            }
+            rec["counters"], rec["resident"] = _flight.deltas(_metrics, snap)
             if mirror is not None:
                 # un-ragged across configs (the PR 12 h2d_bytes=0
                 # discipline): unsharded commits emit an explicit
@@ -1172,18 +1152,21 @@ class BlockChain:
         from .types import Signer
 
         failpoint("insert/before_recover")
-        with _PhaseClock("recover", phases, _metrics):
+        with _PhaseClock("recover", phases, _metrics,
+                         number=block.number):
             token = sender_cacher.recover(
                 Signer(self.config.chain_id), block.transactions)
 
-        with _PhaseClock("verify", phases, _metrics):
+        with _PhaseClock("verify", phases, _metrics,
+                         number=block.number):
             self.engine.verify_header(self.config, header, parent)
             self.validator.validate_body(block)
 
         # join THIS block's recovery batch before execution: losing the
         # race means re-deriving senders one-by-one mid-execute, which
         # duplicates the whole batch's work on small machines
-        with _PhaseClock("recover", phases, _metrics):
+        with _PhaseClock("recover", phases, _metrics,
+                         number=block.number):
             sender_cacher.wait(token)
 
         failpoint("insert/before_execute")
@@ -1218,11 +1201,13 @@ class BlockChain:
 
         try:
             with insert_timer.time():
-                with _PhaseClock("execute", phases, _metrics):
+                with _PhaseClock("execute", phases, _metrics,
+                                 number=block.number):
                     receipts, logs, used_gas = self.processor.process(
                         block, parent, statedb)
                 rec["parallel"] = dict(self.processor.last_parallel)
-                with _PhaseClock("validate", phases, _metrics):
+                with _PhaseClock("validate", phases, _metrics,
+                                 number=block.number):
                     self.validator.validate_state(
                         block, statedb, receipts, used_gas)
         finally:
@@ -1252,7 +1237,8 @@ class BlockChain:
         # block hashes key the snapshot diff layer (coreth CommitWithSnap).
         # The diff-layer attach itself is deferred to the insert-tail
         # worker along with the rawdb writes (see _tail_worker)
-        with _PhaseClock("commit", phases, _metrics):
+        with _PhaseClock("commit", phases, _metrics,
+                         number=block.number):
             root = statedb.commit(
                 self.config.is_eip158(header.number),
                 block_hash=block.hash(),

@@ -332,22 +332,26 @@ class InsertPipeline:
 
         failpoint("insert/before_recover")
         with _PhaseClock("recover", phases, _metrics,
-                         prefix=_PIPE_PREFIX, span_prefix="pipeline/"):
+                         prefix=_PIPE_PREFIX, span_prefix="pipeline/",
+                         number=block.number):
             token = sender_cacher.recover(
                 Signer(chain.config.chain_id), block.transactions)
 
         with _PhaseClock("verify", phases, _metrics,
-                         prefix=_PIPE_PREFIX, span_prefix="pipeline/"):
+                         prefix=_PIPE_PREFIX, span_prefix="pipeline/",
+                         number=block.number):
             self._verify_windowed(entry, parent_entry)
 
         with _PhaseClock("recover", phases, _metrics,
-                         prefix=_PIPE_PREFIX, span_prefix="pipeline/"):
+                         prefix=_PIPE_PREFIX, span_prefix="pipeline/",
+                         number=block.number):
             sender_cacher.wait(token)
 
         failpoint("insert/before_execute")
         t0 = time.monotonic()
         with _PhaseClock("execute", phases, _metrics,
-                         prefix=_PIPE_PREFIX, span_prefix="pipeline/"):
+                         prefix=_PIPE_PREFIX, span_prefix="pipeline/",
+                         number=block.number):
             try:
                 self._speculate(entry, parent_entry)
             except Exception:
@@ -549,7 +553,8 @@ class InsertPipeline:
                     try:
                         with _PhaseClock("fold", phases, _metrics,
                                          prefix=_PIPE_PREFIX,
-                                         span_prefix="pipeline/"):
+                                         span_prefix="pipeline/",
+                                         number=block.number):
                             (statedb, receipts, logs,
                              used_gas) = self._fold_speculation(entry)
                         mode = "spec"
@@ -653,7 +658,8 @@ class InsertPipeline:
             rec["parallel"] = {"mode": "pipeline-spec",
                                "shards": entry.spec_shards,
                                "per_worker": entry.spec_worker_stats}
-            with _PhaseClock("validate", entry.phases, _metrics):
+            with _PhaseClock("validate", entry.phases, _metrics,
+                             number=block.number):
                 chain.validator.validate_state(block, statedb, receipts,
                                                used_gas)
         finally:

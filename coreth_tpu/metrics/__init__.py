@@ -9,6 +9,8 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+from . import spans as _spans
+
 enabled = True
 enabled_expensive = False  # metrics.EnabledExpensive gate
 
@@ -437,15 +439,41 @@ class _NullCtx:
 _NULL_CTX = _NullCtx()
 
 
+class _PhaseTimer:
+    """One phase_timer use: the registry timer, and the span of the same
+    name around it (a no-op unless the span ring or a profiler session
+    is on)."""
+
+    __slots__ = ("_timer", "_span", "_t0")
+
+    def __init__(self, timer: Timer, span):
+        self._timer = timer
+        self._span = span
+        self._t0 = 0.0
+
+    def __enter__(self):
+        self._span.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self._timer.update(time.monotonic() - self._t0)
+        self._span.__exit__(exc_type, exc, tb)
+        return False
+
+
 def phase_timer(name: str, registry: Optional[Registry] = None):
     """Always-on phase-attribution timer for the commit pipeline
     (plan / export / scatter / patch / store decomposition). Unlike
     expensive_timer this is NOT gated: it fires a handful of times per
     block commit, and the regression it guards (the resident-path CPU
-    overhead) must decompose mechanically in every bench run."""
+    overhead) must decompose mechanically in every bench run. It opens
+    a span of the same name around the timer, so the decomposition
+    shows in the span ring and in a profiler trace."""
     if not enabled:
-        return _NULL_CTX
-    return (registry or default_registry).timer(name).time()
+        return _spans.span(name)
+    return _PhaseTimer((registry or default_registry).timer(name),
+                       _spans.span(name))
 
 
 def observe_slo(name: str, seconds: float, exemplar: Optional[str] = None,

@@ -12,7 +12,26 @@ Each record is a plain dict built by core/blockchain during insert:
      "parallel": {"mode": ..., ...},         # optimistic-executor verdict
      "host_mode": bool | None,               # device vs host hashing
      "trace_id": str | None,                 # insert-… id (tracectx)
+     "build": {...} | None,                  # the local build, below
      "accepted": bool, "seq": int}
+
+`resident` and `counters` carry every name of FLIGHT_TIMERS and
+FLIGHT_COUNTERS, zeros included. A timer `resident/phase/<p>` appears as
+`<p>`, `planned/phase/<p>` as `planned/<p>`.
+
+`build` is what `vm.build_block` measured for a block this node built
+(None for a block from a peer), with the same key set on every build,
+host-mode and failed ones included:
+
+    {"phases": {"miner_execute": s, "preview_commit": s, "preverify": s},
+     "resident": {...}, "counters": {...}}   # deltas over the build
+
+`miner_execute` is `miner.commit_new_work()`; `preview_commit` is the
+resident preview commit inside it (the block's own state commit, where
+its program compiles); `preverify` is the `writes=False` insert. The
+build and the later insert cover separate work, so adding a block's
+build and insert counts nothing twice. A failed build lands as a
+`vm/build_failed` event carrying its section.
 
 `parallel` starts present-but-empty and `host_mode`/`counters` are
 stamped in the insert's finally block, so host-fallback and
@@ -29,12 +48,105 @@ the accepted view over RPC.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
-from collections import deque
-from typing import Dict, List, Optional
+from collections import OrderedDict, deque
+from typing import Dict, List, Optional, Tuple
 
 DEFAULT_CAPACITY = 64
+
+# the fields of a commit program's cache key whose change is counted on
+# every plan-cache miss, as `<executor>/plan_cache/miss_field/<field>`
+RESIDENT_KEY_FIELDS = (
+    "n_segments", "seg_shapes", "seg_offsets", "fresh", "classes",
+    "store_cap", "arena_caps", "g_pad", "len_off", "len_rowidx",
+    "lean_bucket")
+PLANNED_KEY_FIELDS = (
+    "n_segments", "seg_shapes", "seg_offsets", "flat_words", "aux")
+
+# counter/timer families snapshotted around each insert and each local
+# build, so the flight record carries per-block deltas rather than
+# process-cumulative values
+FLIGHT_COUNTERS = (
+    "state/snap/hits", "state/snap/misses", "state/snap/generating",
+    "resident/plan_cache/hits", "resident/plan_cache/misses",
+    "resident/compiles",
+    "resident/h2d_bytes", "resident/gather_bytes",
+    "resident/gather_bytes_modeled", "resident/absorb_d2h_bytes",
+    "resident/lean_wire_bytes",
+    "resident/keccak/lanes", "resident/keccak/rate_blocks",
+    "planned/plan_cache/hits", "planned/plan_cache/misses",
+    "planned/compiles", "planned/h2d_bytes",
+    "planned/keccak/lanes", "planned/keccak/rate_blocks",
+    "trie/keccak/batches", "trie/keccak/batch_msgs",
+) + tuple("resident/plan_cache/miss_field/" + f
+          for f in RESIDENT_KEY_FIELDS) + tuple(
+    "planned/plan_cache/miss_field/" + f for f in PLANNED_KEY_FIELDS)
+FLIGHT_TIMERS = (
+    "resident/phase/commit", "resident/phase/preview",
+    "resident/phase/plan", "resident/phase/export",
+    "resident/phase/compile", "resident/phase/compile_trace",
+    "resident/phase/compile_lower", "resident/phase/compile_backend",
+    "resident/phase/scatter", "resident/phase/patch", "resident/phase/store",
+    "resident/phase/wait", "resident/phase/host_hash",
+    "planned/phase/compile_trace", "planned/phase/compile_lower",
+    "planned/phase/compile_backend",
+)
+BUILD_PHASES = ("miner_execute", "preview_commit", "preverify")
+
+
+def timer_key(name: str) -> str:
+    """A flight timer's key in a record's `resident` dict."""
+    if name.startswith("resident/phase/"):
+        return name[len("resident/phase/"):]
+    return name.replace("/phase/", "/", 1)
+
+
+def snapshot(registry) -> Tuple[dict, dict]:
+    """The flight families' cumulative values, to subtract later."""
+    return ({n: registry.counter(n).count() for n in FLIGHT_COUNTERS},
+            {n: registry.timer(n).total() for n in FLIGHT_TIMERS})
+
+
+def deltas(registry, snap: Tuple[dict, dict]) -> Tuple[dict, dict]:
+    """(counters, resident) deltas since `snap`, every name present."""
+    counters0, timers0 = snap
+    counters = {n: registry.counter(n).count() - counters0[n]
+                for n in FLIGHT_COUNTERS}
+    resident = {timer_key(n): registry.timer(n).total() - timers0[n]
+                for n in FLIGHT_TIMERS}
+    return counters, resident
+
+
+class BuildRecorder:
+    """Measures one local block build for its flight record's `build`
+    section: a clock per phase and the flight families' deltas over the
+    whole build."""
+
+    def __init__(self, registry):
+        self._registry = registry
+        self._snap = snapshot(registry)
+        self.phases = dict.fromkeys(BUILD_PHASES, 0.0)
+
+    @contextlib.contextmanager
+    def phase(self, name: str, inner: Optional[Tuple[str, str]] = None):
+        """Time one phase. inner=(phase, timer): the timer's delta over
+        this phase is reported as that phase too (a part of this one)."""
+        timer = self._registry.timer(inner[1]) if inner else None
+        i0 = timer.total() if timer is not None else 0.0
+        t0 = time.monotonic()
+        try:
+            yield
+        finally:
+            self.phases[name] += time.monotonic() - t0
+            if timer is not None:
+                self.phases[inner[0]] += timer.total() - i0
+
+    def section(self) -> Dict[str, dict]:
+        counters, resident = deltas(self._registry, self._snap)
+        return {"phases": dict(self.phases), "resident": resident,
+                "counters": counters}
 
 
 class FlightRecorder:
@@ -45,18 +157,31 @@ class FlightRecorder:
     def __init__(self, capacity: int = DEFAULT_CAPACITY):
         self._ring: deque = deque(maxlen=max(1, int(capacity)))
         self._events: deque = deque(maxlen=max(1, int(capacity)))
+        # build sections of blocks built here, by hash, until the
+        # block's insert record lands
+        self._builds: OrderedDict = OrderedDict()
         self._lock = threading.Lock()
         self._seq = 0
 
     def record(self, rec: Dict[str, object]) -> Dict[str, object]:
         """Append one block record (mutated in place later for the async
-        `write` phase and the accept mark). Returns the same dict."""
+        `write` phase and the accept mark), with the block's build
+        section if this node built it. Returns the same dict."""
         with self._lock:
             self._seq += 1
             rec["seq"] = self._seq
             rec.setdefault("accepted", False)
+            rec["build"] = self._builds.pop(rec.get("hash"), None)
             self._ring.append(rec)
         return rec
+
+    def note_build(self, block_hash: bytes, section: Dict[str, dict]) -> None:
+        """Hold a locally built block's build section for its record."""
+        with self._lock:
+            self._builds[block_hash] = section
+            self._builds.move_to_end(block_hash)
+            while len(self._builds) > (self._ring.maxlen or 1):
+                self._builds.popitem(last=False)
 
     def mark_accepted(self, block_hash: bytes) -> None:
         """Flip `accepted` on the record for this hash (newest match)."""
@@ -133,4 +258,6 @@ def marshal_record(rec: Dict[str, object]) -> Dict[str, object]:
     for k in ("phases", "counters", "resident", "parallel"):
         if isinstance(out.get(k), dict):
             out[k] = dict(out[k])
+    if isinstance(out.get("build"), dict):
+        out["build"] = {k: dict(v) for k, v in out["build"].items()}
     return out

@@ -4,8 +4,9 @@ observability layer; OBSERVABILITY.md has the span taxonomy).
 Design constraints, in order:
 
 1. Near-zero cost when disabled. `span(...)` is a module function that
-   checks ONE module-level bool and returns a shared null context
-   manager — no allocation, no lock, no clock read. The `# hot-path`
+   checks ONE module-level bool and whether a profiler session records
+   (4. below), and returns a shared null context manager — no
+   allocation, no lock, no clock read. The `# hot-path`
    static-analysis rule (SA003) only admits this helper (plus the gated
    timer helpers) inside hot functions for exactly this reason.
 2. Thread-safe with context propagation. Each thread carries its own
@@ -15,15 +16,24 @@ Design constraints, in order:
 3. Exportable. `chrome_trace()` renders the ring as Chrome trace-event
    JSON ("X" complete events, microsecond ts/dur) — loadable directly
    in Perfetto / chrome://tracing.
+4. On the device trace's clock. While a JAX profiler session records
+   (`jax.profiler.start_trace`, the benchmark's `--trace 1`), every
+   span also writes a TraceMe of the same name, its attributes as
+   metadata, into that session — ring on or off — so program spans sit
+   on one timeline with the device ops. The check is one
+   `TraceMe.is_enabled()` call, bound once JAX is loaded: this module
+   never imports JAX itself.
 
-Enable per-process via the `spans-enabled` VM config knob (vm/config),
-the `debug_setSpans` RPC, or the CORETH_TPU_SPANS=1 env override.
+Enable the ring per-process via the `spans-enabled` VM config knob
+(vm/config), the `debug_setSpans` RPC, or the CORETH_TPU_SPANS=1 env
+override.
 """
 
 from __future__ import annotations
 
 import itertools
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -39,6 +49,26 @@ enabled = os.environ.get("CORETH_TPU_SPANS", "").lower() in ("1", "true", "on")
 DEFAULT_RING_SIZE = 4096
 
 
+def _profiler_unbound() -> bool:
+    """Stands in for `TraceMe.is_enabled` until JAX's profiler library
+    is loaded (no session can record before that), then binds it."""
+    global profiling, _TraceMe
+    lib = sys.modules.get("jax._src.lib")
+    prof = getattr(lib, "_profiler", None)
+    if prof is None:
+        return False
+    _TraceMe = prof.TraceMe
+    profiling = prof.TraceMe.is_enabled
+    return profiling()
+
+
+# `profiling()` is True while a JAX profiler session records. Rebound
+# on first use after JAX loads: read it through the module, never
+# import the name
+profiling = _profiler_unbound
+_TraceMe = None
+
+
 class Span:
     """One timed region. Context manager: enter starts the clock and
     pushes onto the owning thread's stack; exit pops, stamps `end`, and
@@ -46,7 +76,7 @@ class Span:
     enabled, so its cost is off the disabled path entirely."""
 
     __slots__ = ("name", "span_id", "parent_id", "start", "end",
-                 "attrs", "tid", "_tracer")
+                 "attrs", "tid", "_tracer", "_tm")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, object]):
         self._tracer = tracer
@@ -57,6 +87,7 @@ class Span:
         self.start = 0.0
         self.end = 0.0
         self.tid = 0
+        self._tm = None
 
     def set_attr(self, key: str, value) -> None:
         self.attrs[key] = value
@@ -75,11 +106,17 @@ class Span:
                 self.parent_id = ctx.parent_span_id
                 self.attrs.setdefault("trace_id", ctx.trace_id)
         stack.append(self)
+        if profiling():
+            self._tm = _TraceMe(self.name, **self.attrs)
+            self._tm.__enter__()
         self.start = time.monotonic()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.end = time.monotonic()
+        if self._tm is not None:
+            self._tm.__exit__(exc_type, exc, tb)
+            self._tm = None
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
         stack = self._tracer._stack()
@@ -200,11 +237,38 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+class _ProfilerSpan:
+    """A span with the ring off while a profiler session records: only
+    the TraceMe, which lands in the session's host plane."""
+
+    __slots__ = ("_tm",)
+
+    def __init__(self, name: str, attrs: Dict[str, object]):
+        self._tm = _TraceMe(name, **attrs)
+
+    def __enter__(self):
+        self._tm.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._tm.__exit__(exc_type, exc, tb)
+        return False
+
+    def set_attr(self, key: str, value) -> None:
+        self._tm.set_metadata(**{key: value})
+
+
 def span(name: str, **attrs):
     """THE instrumentation entry point: `with span("chain/verify"): ...`.
-    One bool check when disabled; a real parented Span when enabled."""
+    With the ring off and no profiler session: one bool check and one
+    `is_enabled()` call, returning the shared null span (no allocation,
+    no clock read). Ring off, session on: a TraceMe only. Ring on: a
+    real parented Span, which writes the TraceMe too while a session
+    records."""
     if not enabled:
-        return _NULL_SPAN
+        if not profiling():
+            return _NULL_SPAN
+        return _ProfilerSpan(name, attrs)
     return tracer.span(name, **attrs)
 
 

@@ -79,7 +79,7 @@ class Worker:
         """commitNewWork (worker.go:118-195) → assembled block."""
         from ..metrics.spans import span
 
-        with span("miner/build"):
+        with span("miner/build", number=self.chain.current_block.number + 1):
             return self._commit_new_work(pending)
 
     def _commit_new_work(self, pending: Optional[Dict[bytes, List[Transaction]]] = None) -> Block:

@@ -810,7 +810,8 @@ class IncrementalTrie:
             def sync():
                 executor.run(export)
                 failpoint("resident/before_absorb")
-                return executor.shard_digests(export)
+                with phase_timer("resident/phase/wait"):
+                    return executor.shard_digests(export)
 
             if timeout is None:
                 parts = sync()
@@ -843,7 +844,8 @@ class IncrementalTrie:
         def sync():
             executor.run(export)
             failpoint("resident/before_absorb")
-            return np.asarray(executor.last_dig)
+            with phase_timer("resident/phase/wait"):
+                return np.asarray(executor.last_dig)
 
         if timeout is None:
             dig = sync()

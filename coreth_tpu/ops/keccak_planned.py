@@ -33,6 +33,7 @@ fan-out + channel joins for the whole-trie commit drain
 from __future__ import annotations
 
 import functools
+from collections import OrderedDict
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -117,11 +118,10 @@ def _make_fused_builder(seg_impl, donate: bool = True):
     Because the program is keyed on the full (blocks, lanes, gstart,
     n_patches) tuple, all word/patch offsets are trace-time constants:
     no metadata upload, no dynamic slicing. Lane bucketing in the native
-    planner keeps the set of distinct tuples small in steady state, and
-    the persistent compilation cache carries compiled programs across
-    processes."""
+    planner keeps the set of distinct tuples small in steady state;
+    PlannedCommit keeps the compiled programs, and the persistent
+    compilation cache carries them across processes."""
 
-    @functools.lru_cache(maxsize=256)
     def build(specs):
         total_lanes = sum(s.lanes for s in specs)
         n_pat_total = sum(s.n_patches for s in specs)
@@ -159,6 +159,18 @@ def _make_fused_builder(seg_impl, donate: bool = True):
     return build
 
 
+def _key_fields(key) -> dict:
+    """A fused planned program's key split into the fields whose change
+    a plan-cache miss counts (metrics.flight.PLANNED_KEY_FIELDS)."""
+    specs, n_words, n_aux = key
+    return {
+        "n_segments": len(specs),
+        "seg_shapes": tuple((s.blocks, s.lanes, s.n_patches) for s in specs),
+        "seg_offsets": tuple(s.gstart for s in specs),
+        "flat_words": n_words, "aux": n_aux,
+    }
+
+
 def _fuse_default() -> bool:
     import os
 
@@ -186,9 +198,36 @@ class PlannedCommit:
         self._step = _default_step if seg_impl is None else _make_step(impl)
         self._fused = _make_fused_builder(impl)
         self.fused = _fuse_default() if fused is None else fused
+        # program cache of the fused path: compiled whole-commit
+        # programs by (specs, flat word count, aux length)
+        self._programs: OrderedDict = OrderedDict()
+        self._last_key = None  # the previous fused commit's key
         self.last_h2d_bytes = 0
         self.last_transfers = 0
         self.last_dispatches = 0
+
+    def _program(self, specs: tuple, fw: jax.Array, ax: jax.Array):
+        """The compiled fused program of one commit shape: a cache hit,
+        or a miss compiled here through JAX's stages (as the resident
+        executor's), counting each key field that changed."""
+        from ..metrics import default_registry
+        from .keccak_resident import compile_stages, count_miss_fields
+
+        key = (specs, fw.shape[0], ax.shape[0])
+        prev, self._last_key = self._last_key, key
+        fn = self._programs.get(key)
+        if fn is not None:
+            default_registry.counter("planned/plan_cache/hits").inc(1)
+            self._programs.move_to_end(key)
+            return fn
+        default_registry.counter("planned/plan_cache/misses").inc(1)
+        if prev is not None:
+            count_miss_fields("planned", _key_fields(prev), _key_fields(key))
+        if len(self._programs) >= 256:
+            self._programs.popitem(last=False)
+        fn = compile_stages("planned", self._fused(specs), fw, ax)
+        self._programs[key] = fn
+        return fn
 
     def run(self, specs: Sequence, flat_words: np.ndarray,
             dst_word: np.ndarray, child_lane: np.ndarray,
@@ -196,14 +235,16 @@ class PlannedCommit:
             want_digests: bool = False) -> Tuple[bytes, Optional[np.ndarray]]:  # hot-path
         """Inputs from CommitPlan.export_words(). Returns (root32,
         dig uint32[G, 8] | None)."""
-        from ..metrics import phase_timer
+        from ..metrics import default_registry, phase_timer
         from .keccak_pallas import count_segments
+        from .keccak_resident import count_keccak_work
 
         n_seg = len(specs)
         if n_seg > MAX_SEGMENTS:
             raise ValueError(f"{n_seg} segments > MAX_SEGMENTS={MAX_SEGMENTS}")
         total_lanes = sum(s.lanes for s in specs)
         count_segments("planned", self._impl, [s.lanes for s in specs])
+        count_keccak_work("planned", [(s.blocks, s.lanes) for s in specs])
 
         if self.fused:
             with phase_timer("planned/phase/scatter"):
@@ -217,8 +258,11 @@ class PlannedCommit:
             self.last_h2d_bytes = flat_words.nbytes + aux.nbytes
             self.last_transfers = 2
             self.last_dispatches = 1
+            default_registry.counter("planned/h2d_bytes").inc(
+                self.last_h2d_bytes)
+            program = self._program(tuple(specs), fw, ax)
             with phase_timer("planned/phase/patch"):
-                dig = self._fused(tuple(specs))(fw, ax)
+                dig = program(fw, ax)
             with phase_timer("planned/phase/store"):
                 if want_digests:
                     host = np.asarray(dig)
@@ -250,6 +294,7 @@ class PlannedCommit:
         dig = jnp.zeros((1 + total_lanes, 8), jnp.uint32)
         self.last_h2d_bytes = (flat_words.nbytes + child_lane.nbytes
                                + dst_word.nbytes + shift.nbytes + meta.nbytes)
+        default_registry.counter("planned/h2d_bytes").inc(self.last_h2d_bytes)
         self.last_transfers = 6
         self.last_dispatches = n_seg
 

@@ -143,6 +143,65 @@ def _make_res_step(seg_impl, donate: bool = True):
     return step
 
 
+def key_fields(key) -> dict:
+    """A fused-program signature split into the fields whose change a
+    plan-cache miss counts (metrics.flight.RESIDENT_KEY_FIELDS). A
+    segment spec is (blocks, lanes, gstart, npatch, patch_off,
+    lane_off): its shape is (blocks, lanes, npatch), its offsets
+    (gstart, patch_off, lane_off)."""
+    (specs_t, fresh_t, classes, store_cap, arena_caps,
+     g_pad, len_off, len_rowidx, lean_bucket) = key
+    return {
+        "n_segments": len(specs_t),
+        "seg_shapes": tuple((s[0], s[1], s[3]) for s in specs_t),
+        "seg_offsets": tuple((s[2], s[4], s[5]) for s in specs_t),
+        "fresh": fresh_t, "classes": classes, "store_cap": store_cap,
+        "arena_caps": arena_caps, "g_pad": g_pad, "len_off": len_off,
+        "len_rowidx": len_rowidx, "lean_bucket": lean_bucket,
+    }
+
+
+def count_miss_fields(executor: str, prev: dict, new: dict) -> None:
+    """On a plan-cache miss: one `<executor>/plan_cache/miss_field/<f>`
+    increment per key field that differs from the previous commit's."""
+    from ..metrics import default_registry
+
+    for name, value in new.items():
+        if prev[name] != value:
+            default_registry.counter(
+                executor + "/plan_cache/miss_field/" + name).inc(1)
+
+
+def count_keccak_work(executor: str, shapes) -> None:
+    """The keccak work one commit hands its kernels, from its segments'
+    (blocks, lanes): `<executor>/keccak/lanes` (Σ lanes) and
+    `<executor>/keccak/rate_blocks` (Σ blocks·lanes, keccak-f[1600]
+    rate blocks absorbed)."""
+    from ..metrics import default_registry
+
+    default_registry.counter(executor + "/keccak/lanes").inc(
+        sum(lanes for _, lanes in shapes))
+    default_registry.counter(executor + "/keccak/rate_blocks").inc(
+        sum(blocks * lanes for blocks, lanes in shapes))
+
+
+def compile_stages(executor: str, jitted, *args):
+    """Compile a jitted commit program ahead of time through JAX's
+    stages, each under its own phase timer and span
+    (`<executor>/phase/compile_trace`, `compile_lower`,
+    `compile_backend`), and count it in `<executor>/compiles`."""
+    from ..metrics import default_registry, phase_timer
+
+    with phase_timer(executor + "/phase/compile_trace"):
+        traced = jitted.trace(*args)
+    with phase_timer(executor + "/phase/compile_lower"):
+        lowered = traced.lower()
+    with phase_timer(executor + "/phase/compile_backend"):
+        compiled = lowered.compile()
+    default_registry.counter(executor + "/compiles").inc(1)
+    return compiled
+
+
 class ResidentExecutor:
     """Holds one trie's device-resident state (store + arenas) and runs
     resident commits exported by native/mpt_inc.cpp's resident planner.
@@ -197,6 +256,7 @@ class ResidentExecutor:
         # commit has settled — never the whole pipeline
         self._fused_cache: dict = {}
         self._staging: dict = {}
+        self._last_key = None  # the previous commit's signature
         self._prepared = None  # (export, its prepare() result) until run
         # bounded in-flight window for deferred-absorb pipelining: 0 =
         # every dispatch settles the previous commit before staging reuse
@@ -416,9 +476,11 @@ class ResidentExecutor:
         program needs only (store, arenas..., rows_packed, aux) and runs
         fresh-row scatters, all segment delta-patch+hash steps, and the
         final store scatter in ONE dispatch. A miss compiles here, ahead
-        of time, so the dispatch itself never waits on the compiler."""
+        of time, so the dispatch itself never waits on the compiler; it
+        counts each key field that differs from the previous commit's."""
         from ..metrics import default_registry
 
+        prev, self._last_key = self._last_key, key
         fn = self._fused_cache.get(key)
         if fn is not None:
             default_registry.counter("resident/plan_cache/hits").inc(1)
@@ -426,19 +488,22 @@ class ResidentExecutor:
             return fn
         default_registry.counter("resident/plan_cache/misses").inc(1)
         self.last_cache_hit = False
+        if prev is not None:
+            count_miss_fields("resident", key_fields(prev), key_fields(key))
         if len(self._fused_cache) >= 256:
-            # bound compiled-program retention (matches the planned
-            # builder's lru_cache(256)); dict preserves insertion order,
+            # bound compiled-program retention (as the planned
+            # executor's program cache); dict preserves insertion order,
             # so this evicts the oldest signature (and its staging)
             oldest = next(iter(self._fused_cache))
             self._fused_cache.pop(oldest)
             self._staging.pop(oldest, None)
         classes = key[2]
         rows, aux = self._upload_shapes(key)
-        compiled = self._fused_jit(key).lower(
-            self.store, *(self.arenas[c] for c in classes),
+        compiled = compile_stages(
+            "resident", self._fused_jit(key), self.store,
+            *(self.arenas[c] for c in classes),
             jax.ShapeDtypeStruct(rows, jnp.uint32),
-            jax.ShapeDtypeStruct(aux, jnp.int32)).compile()
+            jax.ShapeDtypeStruct(aux, jnp.int32))
         self._fused_cache[key] = compiled
         return compiled
 
@@ -620,7 +685,8 @@ class ResidentExecutor:
             if len(ring) >= want:
                 aux, rows_packed, busy = ring.pop(0)
                 if busy is not None and hasattr(busy, "block_until_ready"):
-                    busy.block_until_ready()
+                    with phase_timer("resident/phase/wait"):
+                        busy.block_until_ready()
             else:
                 (n_rows,), (n_aux,) = self._upload_shapes(key)
                 aux = np.zeros(n_aux, np.int32)
@@ -658,6 +724,8 @@ class ResidentExecutor:
             self.last_lean_rows = n_lean
             self.last_lean_wire_bytes = n_lean * (4 * LEAN_WORDS + 8)
 
+        # `patch` times the two uploads and the program's enqueue, not
+        # its run on the device: the wait for that is resident/phase/wait
         with phase_timer("resident/phase/patch"):
             rows_d = self._put(rows_packed[:rp])
             aux_d = self._put(aux)
@@ -701,6 +769,8 @@ class ResidentExecutor:
                     else self._prepare(export))
         specs = export["specs"]
         count_segments("resident", self._impl, [int(s[1]) for s in specs])
+        count_keccak_work("resident", [(int(s[0]), int(s[1]))
+                                       for s in specs])
         if self.fused:
             return self._run_fused(export, prepared)
 
@@ -795,5 +865,10 @@ class ResidentExecutor:
 
     @staticmethod
     def root_bytes(root: jax.Array) -> bytes:
-        """Synchronize and render a run() result as the 32-byte root."""
-        return np.asarray(root).astype("<u4").tobytes()
+        """Synchronize and render a run() result as the 32-byte root;
+        the wait for the device is timed as `resident/phase/wait`."""
+        from ..metrics import phase_timer
+
+        with phase_timer("resident/phase/wait"):
+            host = np.asarray(root)
+        return host.astype("<u4").tobytes()

@@ -105,12 +105,21 @@ class MirrorStateTrie:
             parent = self.mirror.key_for_root(self.root)
             if parent is None:
                 raise MirrorError("root not resident")
-            root = self.mirror.preview(parent, batch,
-                                       expected_root=self.expected_root)
+            root = self._preview(parent, batch)
         except MirrorError:
             root = self._disk_apply().hash()
         self._preview_root = root
         return root
+
+    def _preview(self, parent: bytes, batch) -> bytes:
+        """The mirror's anonymous commit of [batch] on [parent], timed
+        as `resident/phase/preview`: for a block this node builds, this
+        is the block's own state commit."""
+        from ..metrics import phase_timer
+
+        with phase_timer("resident/phase/preview"):
+            return self.mirror.preview(parent, batch,
+                                       expected_root=self.expected_root)
 
     def commit_block(self, block_hash: Optional[bytes],
                      parent_block_hash: Optional[bytes]):
@@ -130,9 +139,7 @@ class MirrorStateTrie:
             if parent is None:
                 raise MirrorError("root not resident")
             if block_hash is None:
-                return self.mirror.preview(
-                    parent, batch,
-                    expected_root=self.expected_root), None
+                return self._preview(parent, batch), None
             return self.mirror.verify(
                 parent, block_hash, batch,
                 expected_root=self.expected_root), None

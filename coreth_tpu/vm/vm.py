@@ -476,7 +476,8 @@ class VM:
         try:
             from ..metrics.spans import span
 
-            with span("vm/buildBlock"):
+            with span("vm/buildBlock",
+                      number=self.blockchain.current_block.number + 1):
                 return self._build_block_inner()
         finally:
             # the engine consumed the PendingTxs notification by calling
@@ -485,22 +486,37 @@ class VM:
             self.block_builder.handle_generate_block()
 
     def _build_block_inner(self) -> VMBlock:
+        from ..metrics import default_registry
+        from ..metrics.flight import BuildRecorder
+
+        recorder = self.blockchain.flight_recorder
         with self.lock:
             self._building_txs = []
+            # the block's flight record gets this build's own section:
+            # its state commit runs (and compiles) in the miner's preview
+            build = BuildRecorder(default_registry)
             try:
-                eth_block = self.miner.commit_new_work()
+                with build.phase("miner_execute", inner=(
+                        "preview_commit", "resident/phase/preview")):
+                    eth_block = self.miner.commit_new_work()
                 if not eth_block.transactions and not self._building_txs:
                     raise VMError("block contains no transactions")
                 vmb = VMBlock(self, eth_block)
                 # verify without writes: re-executes like a peer would
                 vmb.syntactic_verify()
-                self.blockchain.insert_block_manual(eth_block, writes=False)
-            except Exception:
+                with build.phase("preverify"):
+                    self.blockchain.insert_block_manual(eth_block,
+                                                        writes=False)
+            except Exception as e:
+                recorder.note_event("vm/build_failed",
+                                    error=type(e).__name__,
+                                    build=build.section())
                 # requeue any atomic txs popped into 'issued' during the
                 # failed build (vm.go buildBlock error path CancelCurrentTxs)
                 for tx in list(self.mempool.issued.values()):
                     self.mempool.cancel_current_tx(tx.id())
                 raise
+            recorder.note_build(eth_block.hash(), build.section())
             self.mempool.issue_current_txs()
             return vmb
 
