@@ -59,9 +59,8 @@ DEFAULT_CAPACITY = 64
 # the fields of a commit program's cache key whose change is counted on
 # every plan-cache miss, as `<executor>/plan_cache/miss_field/<field>`
 RESIDENT_KEY_FIELDS = (
-    "n_segments", "seg_shapes", "seg_offsets", "fresh", "classes",
-    "store_cap", "arena_caps", "g_pad", "len_off", "len_rowidx",
-    "lean_bucket")
+    "classes", "store_cap", "arena_caps", "g_pad", "patch_bucket",
+    "tile_bucket", "fresh", "lean_bucket")
 PLANNED_KEY_FIELDS = (
     "n_segments", "seg_shapes", "seg_offsets", "flat_words", "aux")
 
@@ -76,6 +75,7 @@ FLIGHT_COUNTERS = (
     "resident/gather_bytes_modeled", "resident/absorb_d2h_bytes",
     "resident/lean_wire_bytes",
     "resident/keccak/lanes", "resident/keccak/rate_blocks",
+    "resident/keccak/pad_lanes",
     "planned/plan_cache/hits", "planned/plan_cache/misses",
     "planned/compiles", "planned/h2d_bytes",
     "planned/keccak/lanes", "planned/keccak/rate_blocks",

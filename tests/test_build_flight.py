@@ -29,9 +29,12 @@ COMPILE_KEYS = ("compile_trace", "compile_lower", "compile_backend")
 
 
 def _vm(prefer_host: bool) -> VM:
+    # enough accounts that the genesis commit's program is sized for a
+    # far larger commit than a block's: the first block compiles its
+    # own program, and the blocks after it reuse that one
     alloc = {priv_to_address(KEY): GenesisAccount(balance=10**24)}
-    for i in range(1, 40):
-        alloc[bytes([i]) * 20] = GenesisAccount(balance=10**18 + i)
+    for i in range(1, 400):
+        alloc[i.to_bytes(20, "big")] = GenesisAccount(balance=10**18 + i)
     genesis = Genesis(config=params.TEST_CHAIN_CONFIG,
                       gas_limit=params.CORTINA_GAS_LIMIT, alloc=alloc)
     vm = VM()

@@ -22,41 +22,73 @@ def _timers(prefix):
             for s in ("trace", "lower", "backend")}
 
 
-def _resident_key(ex, spec):
-    """A one-segment signature of class-1 rows: 16 lanes, 4 patches, in
-    a 32-lane commit; spec = (gstart, patch_off, lane_off)."""
-    gstart, patch_off, lane_off = spec
-    return (((1, 16, gstart, 4, patch_off, lane_off),), (), (1,),
-            ex.store.shape[0], (ex.arenas[1].shape[0],), 32, 8, 32, 0)
+def _resident_chain(seed, n):
+    """A trie of n keys committed resident once (its genesis commit),
+    with a native CPU twin; returns (rng, keys, commit) where commit(batch)
+    applies the batch to both and commits it resident, checks the root
+    against the twin, and returns the export the executor ran."""
+    from coreth_tpu.native.mpt import IncrementalTrie
+    from coreth_tpu.ops.keccak_resident import ResidentExecutor
+
+    rng = np.random.default_rng(seed)
+    state = {rng.bytes(32): rng.bytes(40) for _ in range(n)}
+    dev = IncrementalTrie(sorted(state.items()))
+    cpu = IncrementalTrie(sorted(state.items()))
+    ex = ResidentExecutor()
+    exports = []
+    run = ex.run
+    ex.run = lambda export: (exports.append(export), run(export))[1]
+    assert ex.root_bytes(dev.commit_resident(ex)) == cpu.commit_cpu()
+
+    def commit(batch):
+        dev.update(batch)
+        cpu.update(batch)
+        assert ex.root_bytes(dev.commit_resident(ex)) == cpu.commit_cpu()
+        return ex, exports[-1]
+
+    return rng, sorted(state), commit
 
 
 def test_resident_miss_counts_only_the_offsets_that_moved():
-    from coreth_tpu.ops.keccak_resident import ResidentExecutor
+    """Two block commits whose segment counts and offsets differ but fit
+    the held buckets share one program: the second is a hit and compiles
+    nothing. A commit that outgrows a bucket is one miss, counted under
+    the field of each bucket it outgrew."""
+    from coreth_tpu.ops.keccak_resident import key_fields
 
-    ex = ResidentExecutor()
-    ex._ensure_store(64)
-    ex._ensure_arena(1, 64)
-    a = _resident_key(ex, (0, 0, 0))
-    b = _resident_key(ex, (16, 4, 16))
+    rng, keys, commit = _resident_chain(3, 1500)
+
+    def update(n):
+        picked = rng.choice(len(keys), n, replace=False)
+        return [(keys[i], rng.bytes(40)) for i in picked]
+
+    ex, first = commit(update(60))    # block-sized: its own program
+    a = ex._last_key
     compiles = default_registry.counter("resident/compiles")
     misses = default_registry.counter("resident/plan_cache/misses")
-    c0, m0, f0 = compiles.count(), misses.count(), \
+    hits = default_registry.counter("resident/plan_cache/hits")
+    c0, m0, h0, f0 = compiles.count(), misses.count(), hits.count(), \
         _counts("resident", RESIDENT_KEY_FIELDS)
     t0 = _timers("resident")
-    ex._fused_program(a)              # first program: nothing to compare
+    ex, second = commit(update(45))   # other counts and offsets
+    assert first["specs"].tolist() != second["specs"].tolist()
+    assert first["off"].shape != second["off"].shape
+    assert ex.last_cache_hit and ex._last_key == a
+    assert (compiles.count(), misses.count(), hits.count()) \
+        == (c0, m0, h0 + 1)
     assert _counts("resident", RESIDENT_KEY_FIELDS) == f0
-    ex._fused_program(b)              # same shapes, other offsets
+    assert _timers("resident") == t0
+    burst = [(rng.bytes(32), rng.bytes(40)) for _ in range(400)]
+    ex, _ = commit(burst)             # fresh rows and lanes outgrow
+    b = ex._last_key
+    grown = {f for f, v in key_fields(b).items() if key_fields(a)[f] != v}
     moved = {f: n - f0[f] for f, n in
              _counts("resident", RESIDENT_KEY_FIELDS).items() if n != f0[f]}
-    assert moved == {"seg_offsets": 1}
-    assert compiles.count() - c0 == 2 and misses.count() - m0 == 2
+    assert moved == dict.fromkeys(grown, 1)
+    assert {"g_pad", "fresh"} <= grown
+    assert (compiles.count() - c0, misses.count() - m0) == (1, 1)
     assert {s: n - t0[s] for s, n in _timers("resident").items()} \
-        == {"trace": 2, "lower": 2, "backend": 2}
-    hits = default_registry.counter("resident/plan_cache/hits").count()
-    assert ex._fused_program(a) is not None and ex.last_cache_hit
-    assert default_registry.counter("resident/plan_cache/hits").count() \
-        == hits + 1
-    assert compiles.count() - c0 == 2
+        == {"trace": 1, "lower": 1, "backend": 1}
 
 
 def test_planned_cache_hits_repeats_and_counts_what_changed():

@@ -75,3 +75,25 @@ def test_planned_commit_step_compiles(one_chip):
     compiled = step.lower(*args, lanes=lanes, blocks=blocks,
                           npatch=npatch).compile()
     assert compiled.memory_analysis() is not None
+
+
+def test_resident_commit_program_compiles(one_chip):
+    """The resident executor's whole-commit program (tile loop, switch
+    over arena classes) at a block-sized key: no arena-sized relayout
+    inside the loop, which row-padded arenas keep row-major."""
+    from coreth_tpu.ops.keccak_resident import ResidentExecutor
+
+    ex = ResidentExecutor(fused=True)
+    classes = (1, 2, 3, 4)
+    key = (classes, 8192, (6144,) * 4, 4096, 4096, 32, (1024, 16, 16, 16), 0)
+    (rows,), (aux,) = ex._upload_shapes(key)
+    args = ([_spec((8192, 8), jnp.uint32, one_chip)]
+            + [_spec((6144, ex._arena_width(c)), jnp.uint32, one_chip)
+               for c in classes]
+            + [_spec((rows,), jnp.uint32, one_chip),
+               _spec((aux,), jnp.int32, one_chip)])
+    compiled = ex._fused_jit(key).lower(*args).compile()
+    assert compiled.memory_analysis() is not None
+    arena_copies = [line for line in compiled.as_text().splitlines()
+                    if " copy(" in line and "u32[6144," in line]
+    assert not arena_copies, arena_copies[:2]
